@@ -165,7 +165,7 @@ def main() -> None:
     result = {
         "config": {"archs": args.archs, "batch": args.batch, "seq": args.seq,
                    "reps": args.reps,
-                   "interpret": kops.INTERPRET,
+                   "interpret": kops.interpret(),
                    "baseline": "dense_dgrad + pegrad_norm (2 launches)",
                    "fused": "dense_bwd_norm (1 launch)"},
         "cells": cells,

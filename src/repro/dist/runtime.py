@@ -28,7 +28,6 @@ import threading
 from typing import Callable, Optional, Tuple
 
 import jax
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.dist import sharding as _sh
@@ -108,8 +107,8 @@ def batch_local(fn: Callable, n_batch_args: int,
                 lambda s: P() if s.ndim == 0
                 else P(bax, *(None,) * (s.ndim - 1)), out_abs)
             inner = fn
-        return shard_map(inner, mesh=mesh, in_specs=in_specs,
-                         out_specs=out_specs, check_rep=False)(*args)
+        return jax.shard_map(inner, mesh=mesh, in_specs=in_specs,
+                             out_specs=out_specs, check_vma=False)(*args)
 
     return wrapped
 
@@ -188,7 +187,7 @@ def attn_local(fn: Callable, n_kv: int) -> Callable:
             return fn(q, k, v)
         qs = P(bax, None, kv_ax, None, None)
         ks = P(bax, None, kv_ax, None)
-        return shard_map(fn, mesh=mesh, in_specs=(qs, ks, ks),
-                         out_specs=qs, check_rep=False)(q, k, v)
+        return jax.shard_map(fn, mesh=mesh, in_specs=(qs, ks, ks),
+                             out_specs=qs, check_vma=False)(q, k, v)
 
     return wrapped

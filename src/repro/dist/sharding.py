@@ -30,6 +30,8 @@ from typing import Optional, Sequence, Tuple
 import jax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from repro.launch.mesh import make_mesh
+
 # mesh axes that carry the batch dimension, outermost first
 BATCH_AXES = ("pod", "data")
 MODEL_AXIS = "model"
@@ -47,7 +49,7 @@ _NEVER_SHARD = ("layers",)
 
 def mesh_from_config(cfg) -> Mesh:
     """Build a device mesh from a ``MeshConfig`` (configs/base.py)."""
-    return jax.make_mesh(tuple(cfg.shape), tuple(cfg.axes))
+    return make_mesh(cfg.shape, cfg.axes)
 
 
 def _axis_size(mesh, name: str) -> int:

@@ -6,6 +6,9 @@ materializing the clipped copies ḡ_b in HBM — each (bb, bn) tile is scaled
 by its clip factors and accumulated into the output tile in VMEM.  This is
 the kernel DiVa's PPU datapath performs between the GEMM engine drain and
 the DRAM writeback.
+
+Layouts obey the TPU tiling rule (no rank-1 blocks): the clip factors enter
+as a (B, 1) column and the reduction leaves as a (1, N) row.
 """
 from __future__ import annotations
 
@@ -26,8 +29,8 @@ def _kernel(g_ref, c_ref, out_ref):
         out_ref[...] = jnp.zeros_like(out_ref)
 
     g = g_ref[...].astype(F32)           # (bb, bn)
-    c = c_ref[...].astype(F32)           # (bb,)
-    out_ref[...] += jnp.sum(g * c[:, None], axis=0)
+    c = c_ref[...].astype(F32)           # (bb, 1)
+    out_ref[...] += jnp.sum(g * c, axis=0, keepdims=True)
 
 
 @functools.partial(jax.jit, static_argnames=("bb", "bn", "interpret"))
@@ -39,19 +42,19 @@ def clip_reduce(g: jax.Array, c: jax.Array, *, bb: int = 8, bn: int = 1024,
     bn = min(bn, _rup(N, 128))
     Bp, Np = _rup(B, bb), _rup(N, bn)
     gp = jnp.pad(g, ((0, Bp - B), (0, Np - N)))
-    cp = jnp.pad(c, (0, Bp - B))
+    cp = jnp.pad(c, (0, Bp - B))[:, None]
     out = pl.pallas_call(
         _kernel,
         grid=(Np // bn, Bp // bb),
         in_specs=[
             pl.BlockSpec((bb, bn), lambda n, b: (b, n)),
-            pl.BlockSpec((bb,), lambda n, b: (b,)),
+            pl.BlockSpec((bb, 1), lambda n, b: (b, 0)),
         ],
-        out_specs=pl.BlockSpec((bn,), lambda n, b: (n,)),
-        out_shape=jax.ShapeDtypeStruct((Np,), F32),
+        out_specs=pl.BlockSpec((1, bn), lambda n, b: (0, n)),
+        out_shape=jax.ShapeDtypeStruct((1, Np), F32),
         interpret=interpret,
     )(gp, cp)
-    return out[:N]
+    return out[0, :N]
 
 
 def _rup(n: int, m: int) -> int:

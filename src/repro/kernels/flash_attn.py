@@ -15,6 +15,14 @@ logsumexp; the Pallas pair keeps it in VMEM and is what the ``"fused"``
 norm strategy's attention site dispatches to (core/sites.py), since
 attention itself is parameter-free and contributes an exact zero to the
 per-example norm² side-channel.
+
+Layouts obey the TPU tiling rule: the row statistics (logsumexp, and delta
+in the backward) travel as (BH, 1, T) rows in (1, 1, bq) blocks, and the
+forward's running max/sum live in 2-D (bq, 1) scratch.  The forward turns
+its (bq, 1) logsumexp column into the output row with a diagonal select and
+a sublane sum (exact: one nonzero per column).  The backward kernels work
+on transposed (bk, bq) score tiles, so the rows broadcast as they are and
+no tile is ever transposed.
 """
 from __future__ import annotations
 
@@ -53,12 +61,12 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_ref, l_ref, acc_ref, *,
         if causal:
             mask = jnp.logical_and(mask, kpos <= qpos)
         s = jnp.where(mask, s, NEG)
-        m_prev = m_ref[...]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1))
-        p = jnp.exp(s - m_new[:, None])
+        m_prev = m_ref[...]                              # (bq, 1)
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+        p = jnp.exp(s - m_new)
         corr = jnp.exp(m_prev - m_new)
-        l_ref[...] = l_ref[...] * corr + jnp.sum(p, axis=1)
-        acc_ref[...] = (acc_ref[...] * corr[:, None]
+        l_ref[...] = l_ref[...] * corr + jnp.sum(p, axis=1, keepdims=True)
+        acc_ref[...] = (acc_ref[...] * corr
                         + jax.lax.dot_general(
                             p.astype(v_ref.dtype), v_ref[0],
                             (((1,), (0,)), ((), ())),
@@ -67,9 +75,18 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_ref, l_ref, acc_ref, *,
 
     @pl.when(ki == n_k - 1)
     def _drain():
-        l = jnp.maximum(l_ref[...], 1e-30)
-        o_ref[0] = (acc_ref[...] / l[:, None]).astype(o_ref.dtype)
-        lse_ref[0] = m_ref[...] + jnp.log(l)
+        l = jnp.maximum(l_ref[...], 1e-30)               # (bq, 1)
+        o_ref[0] = (acc_ref[...] / l).astype(o_ref.dtype)
+        lse_ref[0] = _col_to_row(m_ref[...] + jnp.log(l))
+
+
+def _col_to_row(col):
+    """(n, 1) -> (1, n) without a transpose: select the diagonal of the
+    column's broadcast and sum over sublanes."""
+    n = col.shape[0]
+    eye = (jax.lax.broadcasted_iota(jnp.int32, (n, n), 0)
+           == jax.lax.broadcasted_iota(jnp.int32, (n, n), 1))
+    return jnp.sum(jnp.where(eye, col, 0.0), axis=0, keepdims=True)
 
 
 @functools.partial(jax.jit,
@@ -104,17 +121,17 @@ def flash_attn_fwd(q: jax.Array, k: jax.Array, v: jax.Array, *,
         ],
         out_specs=[
             pl.BlockSpec((1, bq, hdp), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((1, bq), lambda b, i, j: (b, i)),
+            pl.BlockSpec((1, 1, bq), lambda b, i, j: (b, 0, i)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((BH, Tp, hdp), q.dtype),
-            jax.ShapeDtypeStruct((BH, Tp), F32),
+            jax.ShapeDtypeStruct((BH, 1, Tp), F32),
         ],
-        scratch_shapes=[_vmem((bq,), F32), _vmem((bq,), F32),
+        scratch_shapes=[_vmem((bq, 1), F32), _vmem((bq, 1), F32),
                         _vmem((bq, hdp), F32)],
         interpret=interpret,
     )(qp, kp, vp)
-    return o[:, :T, :hd], lse[:, :T]
+    return o[:, :T, :hd], lse[:, 0, :T]
 
 
 # ---------------------------------------------------------------------------
@@ -123,30 +140,31 @@ def flash_attn_fwd(q: jax.Array, k: jax.Array, v: jax.Array, *,
 #
 # Standard flash backward from the saved row logsumexp:
 #   p  = exp(s - lse),  ds = p ∘ (do·vᵀ - delta) · scale,  delta = Σ do∘o.
-# The (bq, bk) p/ds tiles live only in VMEM — no B×L×L materialization and
-# no second pass over the scores.  Masking: key-side padding and causality
-# are folded into s (as in the forward); query-side padding rows are zeroed
-# on p directly (their lse slots are meaningless, so exp(s - lse) must not
-# feed the accumulators).  All-zero do rows (masked Poisson examples)
+# The p/ds tiles live only in VMEM, transposed to (bk, bq) so the (1, bq)
+# lse/delta rows broadcast over them — no B×L×L materialization and no
+# second pass over the scores.  Masking: key-side padding and causality
+# are folded into s (as in the forward); query-side padding columns are
+# zeroed on pᵀ directly (their lse slots are meaningless, so exp(s - lse)
+# must not feed the accumulators).  All-zero do rows (masked Poisson examples)
 # annihilate delta, dp, ds and hence all three gradients exactly.
 
 
-def _p_ds(q, k, v, do, lse, delta, qi, ki, *, bq, bk, seq_q, seq_k, causal,
-          scale):
-    """The shared tile recompute: (p, ds), query-padding rows zeroed."""
-    s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+def _p_ds_t(q, k, v, do, lse, delta, qi, ki, *, bq, bk, seq_q, seq_k,
+            causal, scale):
+    """The shared tile recompute, transposed: (pᵀ, dsᵀ) as (bk, bq) tiles,
+    query-padding columns zeroed.  ``lse``/``delta`` are (1, bq) rows."""
+    s = jax.lax.dot_general(k, q, (((1,), (1,)), ((), ())),
                             preferred_element_type=F32) * scale
-    qpos = qi * bq + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 0)
-    kpos = ki * bk + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
+    kpos = ki * bk + jax.lax.broadcasted_iota(jnp.int32, (bk, bq), 0)
+    qpos = qi * bq + jax.lax.broadcasted_iota(jnp.int32, (bk, bq), 1)
     mask = kpos < seq_k
     if causal:
         mask = jnp.logical_and(mask, kpos <= qpos)
     s = jnp.where(mask, s, NEG)
-    rows = qpos < seq_q
-    p = jnp.where(rows, jnp.exp(s - lse[:, None]), 0.0)
-    dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
+    p = jnp.where(qpos < seq_q, jnp.exp(s - lse), 0.0)
+    dp = jax.lax.dot_general(v, do, (((1,), (1,)), ((), ())),
                              preferred_element_type=F32)
-    ds = p * (dp - delta[:, None]) * scale
+    ds = p * (dp - delta) * scale
     return p, ds
 
 
@@ -166,13 +184,15 @@ def _bwd_kv_kernel(q_ref, do_ref, lse_ref, delta_ref, k_ref, v_ref,
     @pl.when(run)
     def _block():
         q, do = q_ref[0], do_ref[0]
-        p, ds = _p_ds(q, k_ref[0], v_ref[0], do, lse_ref[0], delta_ref[0],
-                      qi, ki, bq=bq, bk=bk, seq_q=seq_q, seq_k=seq_k,
-                      causal=causal, scale=scale)
+        pt, dst = _p_ds_t(q, k_ref[0], v_ref[0], do, lse_ref[0],
+                          delta_ref[0], qi, ki, bq=bq, bk=bk, seq_q=seq_q,
+                          seq_k=seq_k, causal=causal, scale=scale)
         dvacc_ref[...] += jax.lax.dot_general(
-            p, do, (((0,), (0,)), ((), ())), preferred_element_type=F32)
+            pt, do.astype(F32), (((1,), (0,)), ((), ())),
+            preferred_element_type=F32)
         dkacc_ref[...] += jax.lax.dot_general(
-            ds, q, (((0,), (0,)), ((), ())), preferred_element_type=F32)
+            dst, q.astype(F32), (((1,), (0,)), ((), ())),
+            preferred_element_type=F32)
 
     @pl.when(qi == n_q - 1)
     def _drain():
@@ -193,11 +213,12 @@ def _bwd_q_kernel(q_ref, do_ref, lse_ref, delta_ref, k_ref, v_ref,
 
     @pl.when(run)
     def _block():
-        _, ds = _p_ds(q_ref[0], k_ref[0], v_ref[0], do_ref[0], lse_ref[0],
-                      delta_ref[0], qi, ki, bq=bq, bk=bk, seq_q=seq_q,
-                      seq_k=seq_k, causal=causal, scale=scale)
+        k = k_ref[0]
+        _, dst = _p_ds_t(q_ref[0], k, v_ref[0], do_ref[0], lse_ref[0],
+                         delta_ref[0], qi, ki, bq=bq, bk=bk, seq_q=seq_q,
+                         seq_k=seq_k, causal=causal, scale=scale)
         dqacc_ref[...] += jax.lax.dot_general(
-            ds, k_ref[0], (((1,), (0,)), ((), ())),
+            dst, k.astype(F32), (((0,), (0,)), ((), ())),
             preferred_element_type=F32)
 
     @pl.when(ki == n_k - 1)
@@ -225,13 +246,13 @@ def flash_attn_bwd(q: jax.Array, k: jax.Array, v: jax.Array, o: jax.Array,
     qp, dop = _pad(q, _rup(T, bq), hdp), _pad(do, _rup(T, bq), hdp)
     kp, vp = _pad(k, _rup(S, bk), hdp), _pad(v, _rup(S, bk), hdp)
     Tp, Sp = qp.shape[1], kp.shape[1]
-    lsep = _pad2(lse.astype(F32), Tp)
-    deltap = _pad2(delta, Tp)
+    lsep = _pad2(lse.astype(F32), Tp)[:, None, :]       # (BH, 1, Tp) rows
+    deltap = _pad2(delta, Tp)[:, None, :]
     n_q, n_k = Tp // bq, Sp // bk
     kw = dict(bq=bq, bk=bk, seq_q=T, seq_k=S, causal=causal, scale=scale)
 
     qspec = pl.BlockSpec((1, bq, hdp), lambda b, x, y: (b, y, 0))
-    rspec = pl.BlockSpec((1, bq), lambda b, x, y: (b, y))
+    rspec = pl.BlockSpec((1, 1, bq), lambda b, x, y: (b, 0, y))
     kspec = pl.BlockSpec((1, bk, hdp), lambda b, x, y: (b // rep, x, 0))
     dkh, dvh = pl.pallas_call(
         functools.partial(_bwd_kv_kernel, n_q=n_q, **kw),
@@ -244,7 +265,7 @@ def flash_attn_bwd(q: jax.Array, k: jax.Array, v: jax.Array, o: jax.Array,
     )(qp, dop, lsep, deltap, kp, vp)
 
     qspec2 = pl.BlockSpec((1, bq, hdp), lambda b, x, y: (b, x, 0))
-    rspec2 = pl.BlockSpec((1, bq), lambda b, x, y: (b, x))
+    rspec2 = pl.BlockSpec((1, 1, bq), lambda b, x, y: (b, 0, x))
     kspec2 = pl.BlockSpec((1, bk, hdp), lambda b, x, y: (b // rep, y, 0))
     dq = pl.pallas_call(
         functools.partial(_bwd_q_kernel, n_k=n_k, **kw),

@@ -24,6 +24,9 @@ the kernel lets dead-code elimination remove it (core/context.py).
 Output-revisit discipline (valid on real TPUs, not just interpret mode):
 the gx block (b, t, i) is written exactly once; the nsq block (b,) is
 revisited only across the contiguous (i, t, j) inner loops of a fixed b.
+The nsq output is lane-dense, (BG, 1, 128) with every lane holding the
+running sum (the TPU tiling rule admits no (1,) block); the wrapper reads
+lane 0.
 
 VMEM budget: the slab holds one (bi, do_pad) f32 row strip of G_b —
 ``bi * do_pad * 4`` bytes (4 MB at bi=128, do=8192), beside the (bt, bi)
@@ -47,6 +50,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 F32 = jnp.float32
+LANES = 128   # lane width of a TPU vreg: per-example scalars go out lane-dense
 
 
 def _fused_kernel(x_ref, gy_ref, w_ref, gx_ref, nsq_ref, gxacc_ref, slab_ref,
@@ -84,7 +88,7 @@ def _fused_kernel(x_ref, gy_ref, w_ref, gx_ref, nsq_ref, gxacc_ref, slab_ref,
     @pl.when(jnp.logical_and(t == n_t - 1, j == n_j - 1))
     def _drain_nsq():                # the PPU: reduce the finished strip
         g = slab_ref[...]
-        nsq_ref[0] += jnp.sum(g * g)
+        nsq_ref[...] += jnp.sum(g * g)
 
 
 def _dgrad_kernel(gy_ref, w_ref, gx_ref, gxacc_ref, *, n_j: int):
@@ -142,16 +146,16 @@ def dense_bwd_norm(x: jax.Array, gy: jax.Array, w: jax.Array, *,
         ],
         out_specs=[
             pl.BlockSpec((1, bt, bi), lambda b, i, t, j: (b, t, i)),
-            pl.BlockSpec((1,), lambda b, i, t, j: (b,)),
+            pl.BlockSpec((1, 1, LANES), lambda b, i, t, j: (b, 0, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((BG, Tp, dip), x.dtype),
-            jax.ShapeDtypeStruct((BG,), F32),
+            jax.ShapeDtypeStruct((BG, 1, LANES), F32),
         ],
         scratch_shapes=[_vmem((bt, bi), F32), _vmem((bi, dop), F32)],
         interpret=interpret,
     )(xp, gyp, wp)
-    return gx[:, :T, :di], nsq
+    return gx[:, :T, :di], nsq[:, 0, 0]
 
 
 @functools.partial(jax.jit, static_argnames=("bt", "bi", "bj", "interpret"))

@@ -12,6 +12,12 @@ Grid: (BG, n_t, n_s, n_d) with d innermost (Gram accumulation), using
 symmetry: tiles with s > t are skipped at the index level by mapping them
 to the (t, t) diagonal tile and masking — off-diagonal tiles are counted
 twice via a factor-2 weight, halving FLOPs vs the naive sweep.
+
+Layouts obey the TPU tiling rule (no rank-1 or (1, bt) blocks): the ids
+enter twice, as a (BG, T, 1) column for the t side and a (BG, 1, T) row for
+the s side, so the (bt, bs) id mask is a broadcast compare with no
+in-kernel transpose; each example's norm is written lane-dense into a
+(1, 1, 128) output block and read back from lane 0.
 """
 from __future__ import annotations
 
@@ -22,6 +28,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 F32 = jnp.float32
+LANES = 128   # lane width of a TPU vreg: per-example scalars go out lane-dense
 
 
 def _kernel(xt_ref, xs_ref, gt_ref, gs_ref, idt_ref, ids_ref, out_ref,
@@ -55,10 +62,10 @@ def _kernel(xt_ref, xs_ref, gt_ref, gs_ref, idt_ref, ids_ref, out_ref,
     def _drain():
         prod = a_ref[...] * c_ref[...] if square else c_ref[...]
         if use_mask:
-            m = idt_ref[0][:, None] == ids_ref[0][None, :]
+            m = idt_ref[0] == ids_ref[0]     # (bt, 1) == (1, bs)
             prod = jnp.where(m, prod, 0.0)
         w = jnp.where(s == t, 1.0, 2.0)  # off-diagonal tiles counted twice
-        out_ref[0] += w * jnp.sum(prod)
+        out_ref[...] += w * jnp.sum(prod)
 
 
 @functools.partial(jax.jit,
@@ -95,6 +102,7 @@ def gram_norm(x: jax.Array, gy: jax.Array, mask_ids: jax.Array | None = None,
         ids = _pad_ids(mask_ids, bt, sentinel=-1)
     else:
         ids = jnp.zeros((BG, Tp), jnp.int32)
+    ids_col, ids_row = ids[:, :, None], ids[:, None, :]
 
     out = pl.pallas_call(
         functools.partial(_kernel, n_d=n_d, use_mask=use_mask, square=square),
@@ -104,15 +112,16 @@ def gram_norm(x: jax.Array, gy: jax.Array, mask_ids: jax.Array | None = None,
             pl.BlockSpec((1, bt, bdx), lambda b, t, s, d: (b, jnp.minimum(s, t), d)),
             pl.BlockSpec((1, bt, bdg), lambda b, t, s, d: (b, t, d)),
             pl.BlockSpec((1, bt, bdg), lambda b, t, s, d: (b, jnp.minimum(s, t), d)),
-            pl.BlockSpec((1, bt), lambda b, t, s, d: (b, t)),
-            pl.BlockSpec((1, bt), lambda b, t, s, d: (b, jnp.minimum(s, t))),
+            pl.BlockSpec((1, bt, 1), lambda b, t, s, d: (b, t, 0)),
+            pl.BlockSpec((1, 1, bt),
+                         lambda b, t, s, d: (b, 0, jnp.minimum(s, t))),
         ],
-        out_specs=pl.BlockSpec((1,), lambda b, t, s, d: (b,)),
-        out_shape=jax.ShapeDtypeStruct((BG,), F32),
+        out_specs=pl.BlockSpec((1, 1, LANES), lambda b, t, s, d: (b, 0, 0)),
+        out_shape=jax.ShapeDtypeStruct((BG, 1, LANES), F32),
         scratch_shapes=[_vmem((bt, bt), F32), _vmem((bt, bt), F32)],
         interpret=interpret,
-    )(xp, xp, gyp, gyp, ids, ids)
-    return out
+    )(xp, xp, gyp, gyp, ids_col, ids_row)
+    return out[:, 0, 0]
 
 
 def _vmem(shape, dtype):

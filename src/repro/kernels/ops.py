@@ -1,8 +1,16 @@
 """jit'd public wrappers for the Pallas kernels.
 
-``INTERPRET`` defaults to True because this container is CPU-only; on a
-real TPU deployment set ``repro.kernels.ops.INTERPRET = False`` (or the
-REPRO_PALLAS_INTERPRET=0 env var) and the same kernels compile to Mosaic.
+Each wrapper decides at trace time how its kernel runs (``interpret()``):
+compiled by Mosaic when the default backend is a TPU, in the Pallas
+interpreter on any other backend (the CPU test suite).  There is no switch
+that runs the interpreter on a TPU.
+
+Mosaic kernels cannot be partitioned by GSPMD, so under an active batch
+layout (dist/runtime.py ``layout``, set by the launchers around tracing)
+every per-example wrapper runs its kernel batch-locally under
+``shard_map``: each device sees only its examples, and ``clip_reduce``'s
+batch sum is a ``psum``.  Outside a layout the wrappers call the kernels
+directly.
 
 The DP core reaches these through the site registry: each site kind's
 ``kernel_route`` (core/sites.py) maps its named norm strategies onto these
@@ -24,20 +32,30 @@ import os
 import jax
 import jax.numpy as jnp
 
+from repro.dist import runtime
 from repro.kernels import clip_reduce as _cr
 from repro.kernels import fused_bwd as _fb
 from repro.kernels import gram_norm as _gn
 from repro.kernels import pegrad_norm as _pn
 
-INTERPRET = os.environ.get("REPRO_PALLAS_INTERPRET", "1") != "0"
+
+def interpret() -> bool:
+    """Whether the kernels run in the Pallas interpreter: only off-TPU.
+    Called while tracing, never at import, so it sees the backend the
+    computation is traced for."""
+    return jax.default_backend() != "tpu"
 
 
 def pegrad_norm(x4: jax.Array, gy4: jax.Array) -> jax.Array:
     """(B,G,T,di),(B,G,T,do) -> (B,) fused per-example grad norms²."""
+    return runtime.batch_local(_pegrad_norm, 2)(x4, gy4)
+
+
+def _pegrad_norm(x4, gy4):
     B, G, T, di = x4.shape
     do = gy4.shape[-1]
     out = _pn.pegrad_norm(x4.reshape(B * G, T, di), gy4.reshape(B * G, T, do),
-                          interpret=INTERPRET)
+                          interpret=interpret())
     return out.reshape(B, G).sum(axis=1)
 
 
@@ -45,20 +63,27 @@ def gram_norm(x4: jax.Array, gy4: jax.Array,
               mask_ids: jax.Array | None = None,
               square: bool = True) -> jax.Array:
     """(B,G,T,di),(B,G,T,do)[, ids (B,T)] -> (B,) ghost norms²."""
-    B, G, T, di = x4.shape
-    do = gy4.shape[-1]
-    ids = None
-    if mask_ids is not None:
-        assert G == 1, "id mask only used for embeddings (G == 1)"
-        ids = mask_ids.reshape(B, T)
-    out = _gn.gram_norm(x4.reshape(B * G, T, di), gy4.reshape(B * G, T, do),
-                        ids, interpret=INTERPRET, square=square)
-    return out.reshape(B, G).sum(axis=1)
+    def run(x4, gy4, *mask_ids):
+        B, G, T, di = x4.shape
+        do = gy4.shape[-1]
+        ids = None
+        if mask_ids:
+            assert G == 1, "id mask only used for embeddings (G == 1)"
+            ids = mask_ids[0].reshape(B, T)
+        out = _gn.gram_norm(x4.reshape(B * G, T, di),
+                            gy4.reshape(B * G, T, do), ids,
+                            interpret=interpret(), square=square)
+        return out.reshape(B, G).sum(axis=1)
+
+    args = (x4, gy4) if mask_ids is None else (x4, gy4, mask_ids)
+    return runtime.batch_local(run, len(args))(*args)
 
 
 def clip_reduce(g: jax.Array, c: jax.Array) -> jax.Array:
     """(B, N), (B,) -> (N,) Σ_b c_b g_b."""
-    return _cr.clip_reduce(g, c, interpret=INTERPRET)
+    return runtime.batch_local(
+        lambda g, c: _cr.clip_reduce(g, c, interpret=interpret()), 2,
+        reduce_out=True)(g, c)
 
 
 def dense_bwd_norm(x4: jax.Array, gy4: jax.Array, w: jax.Array):
@@ -66,12 +91,16 @@ def dense_bwd_norm(x4: jax.Array, gy4: jax.Array, w: jax.Array):
     (B,G,T,di), (B,G,T,do), w (di,do) or (G,di,do) ->
     (gx4 (B,G,T,di), nsq (B,) f32) in one kernel sweep
     (kernels/fused_bwd.py)."""
+    return runtime.batch_local(_dense_bwd_norm, 2)(x4, gy4, w)
+
+
+def _dense_bwd_norm(x4, gy4, w):
     B, G, T, di = x4.shape
     do = gy4.shape[-1]
     wE = w if w.ndim == 3 else w[None]
     gx, nsq = _fb.dense_bwd_norm(x4.reshape(B * G, T, di),
                                  gy4.reshape(B * G, T, do), wE,
-                                 interpret=INTERPRET)
+                                 interpret=interpret())
     return gx.reshape(x4.shape), nsq.reshape(B, G).sum(axis=1)
 
 
@@ -80,9 +109,14 @@ def dense_dgrad(gy4: jax.Array, w: jax.Array) -> jax.Array:
     gx4 (B,G,T,di).  Paired with ``pegrad_norm`` in
     benchmarks/kernel_bench.py as the two-launch baseline the fusion is
     gated against."""
+    return runtime.batch_local(_dense_dgrad, 1)(gy4, w)
+
+
+def _dense_dgrad(gy4, w):
     B, G, T, do = gy4.shape
     wE = w if w.ndim == 3 else w[None]
-    gx = _fb.dense_dgrad(gy4.reshape(B * G, T, do), wE, interpret=INTERPRET)
+    gx = _fb.dense_dgrad(gy4.reshape(B * G, T, do), wE,
+                         interpret=interpret())
     return gx.reshape(B, G, T, wE.shape[1])
 
 
@@ -116,7 +150,7 @@ def _flash_fwd_impl(q, k, v, causal):
     kf = k.transpose(0, 2, 1, 3).reshape(B * KV, S, hd)
     vf = v.transpose(0, 2, 1, 3).reshape(B * KV, S, hd)
     o, lse = _fa.flash_attn_fwd(qf, kf, vf, causal=causal, rep=rep,
-                                interpret=INTERPRET)
+                                interpret=interpret())
     o = o.reshape(B, KV, rep, T, hd).transpose(0, 3, 1, 2, 4)
     lse = lse.reshape(B, KV, rep, T)
     return o, lse
@@ -144,7 +178,7 @@ def _flash_bwd_pallas(q, k, v, o, lse, do, causal):
     dqf, dkf, dvf = _fa.flash_attn_bwd(
         flat_q(q), flat_kv(k), flat_kv(v), flat_q(o),
         lse.reshape(B * KV * rep, T), flat_q(do), causal=causal, rep=rep,
-        interpret=INTERPRET)
+        interpret=interpret())
     dq = dqf.reshape(B, KV, rep, T, hd).transpose(0, 3, 1, 2, 4)
     dk = dkf.reshape(B, KV, S, hd).transpose(0, 2, 1, 3)
     dv = dvf.reshape(B, KV, S, hd).transpose(0, 2, 1, 3)
@@ -158,8 +192,11 @@ def flash_attention_bwd(q, k, v, do, causal: bool = True):
     the parameter-free attention op is exactly zero, so the fused content
     here is the kernelized backward itself.  Layouts as in
     ``flash_attention``; returns f32 grads."""
-    o, lse = _flash_fwd_impl(q, k, v, causal)
-    return _flash_bwd_pallas(q, k, v, o, lse, do, causal)
+    def run(q, k, v, do):
+        o, lse = _flash_fwd_impl(q, k, v, causal)
+        return _flash_bwd_pallas(q, k, v, o, lse, do, causal)
+
+    return runtime.batch_local(run, 4)(q, k, v, do)
 
 
 def _flash_vjp_bwd(causal, bwd_block, res, do):

@@ -12,7 +12,10 @@ during gradient post-processing").
 
 Grid: (BG, n_di, n_do, n_t) with t innermost so the VMEM accumulator tile
 is live across exactly the t-loop.  Block shapes are MXU-aligned
-(128 lanes; t-tile a multiple of 8).
+(128 lanes; t-tile a multiple of 8).  Each example's norm² is written
+lane-dense into a (1, 1, 128) output block (every lane holds the same
+partial sum), because the TPU tiling rule admits no (1,) block over a
+(BG,) array; the wrapper reads lane 0.
 """
 from __future__ import annotations
 
@@ -23,6 +26,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 F32 = jnp.float32
+LANES = 128   # lane width of a TPU vreg: per-example scalars go out lane-dense
 
 
 def _kernel(x_ref, gy_ref, out_ref, acc_ref, *, n_t: int, n_i: int, n_j: int):
@@ -45,7 +49,7 @@ def _kernel(x_ref, gy_ref, out_ref, acc_ref, *, n_t: int, n_i: int, n_j: int):
     @pl.when(t == n_t - 1)
     def _drain():                    # the PPU: reduce the finished tile
         g = acc_ref[...]
-        out_ref[0] += jnp.sum(g * g)
+        out_ref[...] += jnp.sum(g * g)
 
 
 @functools.partial(jax.jit, static_argnames=("bt", "bi", "bj", "interpret"))
@@ -72,12 +76,12 @@ def pegrad_norm(x: jax.Array, gy: jax.Array, *, bt: int = 128, bi: int = 128,
             pl.BlockSpec((1, bt, bi), lambda b, i, j, t: (b, t, i)),
             pl.BlockSpec((1, bt, bj), lambda b, i, j, t: (b, t, j)),
         ],
-        out_specs=pl.BlockSpec((1,), lambda b, i, j, t: (b,)),
-        out_shape=jax.ShapeDtypeStruct((BG,), F32),
+        out_specs=pl.BlockSpec((1, 1, LANES), lambda b, i, j, t: (b, 0, 0)),
+        out_shape=jax.ShapeDtypeStruct((BG, 1, LANES), F32),
         scratch_shapes=[_vmem((bi, bj), F32)],
         interpret=interpret,
     )(xp, gyp)
-    return out
+    return out[:, 0, 0]
 
 
 def _vmem(shape, dtype):

@@ -16,6 +16,7 @@ import jax
 import numpy as np
 
 from repro.configs import get_arch, reduced
+from repro.launch import compile_cache
 from repro.models.transformer import build_model
 from repro.serve import Engine, HostLoopEngine, PrivacyLedger, Request
 from repro.serve.scheduler import Scheduler
@@ -67,6 +68,7 @@ def main() -> None:
     ap.add_argument("--ledger-delta", type=float, default=1e-6)
     args = ap.parse_args()
 
+    compile_cache.enable()
     arch = get_arch(args.arch)
     if args.reduced:
         arch = reduced(arch)

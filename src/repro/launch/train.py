@@ -11,6 +11,9 @@ Single-process CPU (default) or multi-controller TPU fleet:
   python -m repro.launch.train --arch phi3-mini-3.8b --reduced --steps 20 \
       --set dp.noise_multiplier=0.8 --set optim.lr=3e-4
 
+``main(argv)`` also runs in-process (chip_smoke.py does) and returns the
+trainer, the final state and the launch memory report.
+
 The loop is the same fault-tolerant ``Trainer`` the tests exercise;
 at fleet scale the step function is pjit-sharded over the production mesh
 and each host feeds its deterministic shard of the global batch.
@@ -30,12 +33,13 @@ from repro.configs.base import ShapeConfig, TrainConfig
 from repro.dist import batch_shardings, runtime, state_shardings
 from repro.dist.sharding import (batch_axis_width, batch_pspec,
                                  stage_axis_width)
+from repro.launch import compile_cache
 from repro.launch.mesh import make_host_mesh, make_mesh
 from repro.models import build_model_for
 from repro.train import Trainer
 
 
-def main() -> None:
+def main(argv=None):
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True)
     ap.add_argument("--shape", default="train_4k")
@@ -56,8 +60,9 @@ def main() -> None:
     ap.add_argument("--coordinator", default=None)
     ap.add_argument("--num-processes", type=int, default=None)
     ap.add_argument("--process-id", type=int, default=None)
-    args = ap.parse_args()
+    args = ap.parse_args(argv)
 
+    compile_cache.enable()
     if args.coordinator:
         jax.distributed.initialize(coordinator_address=args.coordinator,
                                    num_processes=args.num_processes,
@@ -157,7 +162,8 @@ def main() -> None:
               f"per-example side-channel "
               f"{rep['per_example_grad_bytes'] / 1e9:.3f} GB)"
               + (f"; compiled peak {xla / 1e9:.3f} GB "
-                 f"(estimate/xla {rep['estimate_vs_xla']:.2f})"
+                 f"(estimate/xla {rep['estimate_vs_xla']:.2f}, "
+                 f"compiled in {rep['compile_s']:.1f} s)"
                  if xla else ""))
         from repro.launch.memory import per_device_peak_bytes
         per_dev = per_device_peak_bytes(rep, batch_axis_width(mesh),
@@ -173,6 +179,7 @@ def main() -> None:
               f"privacy spent: eps={eps:.3f} "
               f"(delta={cfg.dp.delta}, sampling={cfg.dp.sampling}, "
               f"q={trainer.sample_rate:.2e})")
+    return trainer, state, rep
 
 
 if __name__ == "__main__":

@@ -262,6 +262,8 @@ class Trainer:
         and the step is jitted, XLA's own ``memory_analysis`` numbers
         (``xla_*`` keys) and the estimate/XLA ratio — the launcher logs
         this once per launch so estimator drift is visible in every run.
+        ``compile_s`` is the wall time of that compile (or of its load from
+        the persistent compilation cache).
 
         Scale note: the estimate is *global* (pre-partitioning) while
         XLA's numbers are *per device*, so on an N-device mesh a healthy
@@ -275,8 +277,10 @@ class Trainer:
         est = estimate_train_memory(self.model, self.cfg, abstract,
                                     expected_batch_size=expected)
         if compile and hasattr(self.step_fn, "lower"):
-            mem = self.step_fn.lower(state, batch, key).compile() \
-                      .memory_analysis()
+            t0 = time.perf_counter()
+            compiled = self.step_fn.lower(state, batch, key).compile()
+            est["compile_s"] = time.perf_counter() - t0
+            mem = compiled.memory_analysis()
             if mem is not None:
                 xla_total = (mem.temp_size_in_bytes
                              + mem.argument_size_in_bytes

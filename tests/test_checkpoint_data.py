@@ -9,6 +9,7 @@ import pytest
 
 from repro.configs.base import ShapeConfig
 from repro.data import MemmapSource, SyntheticSource, batch_for, make_source
+from repro.launch.mesh import make_mesh
 from repro.train.checkpoint import CheckpointManager
 
 
@@ -59,7 +60,7 @@ def test_no_partial_checkpoints_visible(tmp_path, key):
 def test_restore_with_shardings(tmp_path, key):
     """Reshard-on-restore: explicit (single-device) shardings path."""
     from jax.sharding import NamedSharding, PartitionSpec as P
-    mesh = jax.make_mesh((1,), ("data",))
+    mesh = make_mesh((1,), ("data",))
     cm = CheckpointManager(str(tmp_path), use_async=False)
     st = _state(key)
     cm.save(st, 1)

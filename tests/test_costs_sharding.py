@@ -140,13 +140,14 @@ from repro.configs import ARCHS, reduced
 from repro.configs.base import DPConfig, OptimConfig
 from repro.core import make_noisy_grad_fn
 from repro.dist import batch_shardings, state_shardings
+from repro.launch.mesh import make_mesh
 from repro.models.transformer import build_model
 from repro.optim import make_optimizer
 from repro.train.state import TrainState
 
 arch = reduced(ARCHS["chatglm3-6b"])
 model = build_model(arch, param_dtype="float32", compute_dtype="float32")
-mesh = jax.make_mesh((2, 4), ("data", "model"))
+mesh = make_mesh((2, 4), ("data", "model"))
 grad_fn = make_noisy_grad_fn(model.loss_fn, DPConfig(algo="dpsgd_r"))
 opt = make_optimizer(OptimConfig(name="adamw"))
 

@@ -16,6 +16,7 @@ from repro.dist import runtime
 from repro.dist.sharding import (_axis_size, batch_pspec, batch_shardings,
                                  param_shardings, spec_for_param,
                                  state_shardings)
+from repro.launch.mesh import make_mesh
 
 
 class _FakeMesh:
@@ -99,7 +100,7 @@ def test_single_device_shardings_run(key):
     from repro.configs.base import OptimConfig
     from repro.train.state import TrainState
 
-    mesh = jax.make_mesh((1,), ("data",))
+    mesh = make_mesh((1,), ("data",))
     arch = reduced(ARCHS["stablelm-3b"])
     model = build_model(arch, param_dtype="float32", compute_dtype="float32")
     params = model.init(key)
@@ -168,10 +169,11 @@ from repro.configs import ARCHS, reduced
 from repro.configs.base import DPConfig
 from repro.dist import runtime, batch_shardings
 from repro.dist.sharding import batch_pspec
+from repro.launch.mesh import make_mesh
 from repro.models.transformer import build_model
 
 assert jax.device_count() == 8
-mesh = jax.make_mesh((8,), ("data",))
+mesh = make_mesh((8,), ("data",))
 bax = batch_pspec(mesh, 8)
 assert bax == ("data",)
 
@@ -216,7 +218,7 @@ assert r2 < 1e-5, f"psum clipped-sum mismatch {r2}"
 
 # --- attn_local: flash attention with batch AND KV-head sharding ----------
 from repro.kernels import ops as kops
-mesh42 = jax.make_mesh((4, 2), ("data", "model"))
+mesh42 = make_mesh((4, 2), ("data", "model"))
 KV, rep, hd = 2, 2, 8
 q = jax.random.normal(jax.random.fold_in(key, 8), (B, T, KV, rep, hd))
 kk = jax.random.normal(jax.random.fold_in(key, 9), (B, T, KV, hd))
