@@ -252,7 +252,8 @@ def _dpsgd_sum(loss_fn, dp: DPConfig):
             cdata, cm = chunk
             losses, gb = jax.vmap(
                 lambda ex, mi: one_example_grad(params, ex, mi))(cdata, cm)
-            summed, nsq = clipping.clip_and_sum(gb, C, mask=cm)
+            with jax.named_scope("dp_clip"):
+                summed, nsq = clipping.clip_and_sum(gb, C, mask=cm)
             acc = jax.tree.map(lambda a, s: a + s.astype(jnp.float32),
                                acc, summed)
             return acc, (losses, nsq)
@@ -297,7 +298,8 @@ def _dpsgd_r_sum(loss_fn, dp: DPConfig):
         # params cotangent is discarded -> its weight-grad GEMMs are DCE'd.
         _, nsq = pull((jnp.ones(()), jnp.zeros((B,), jnp.float32)))
 
-        c = clipping.clip_factors(nsq, C) * me                 # line 35
+        with jax.named_scope("dp_clip"):
+            c = clipping.clip_factors(nsq, C) * me             # line 35
         crow = _expand_rows(c, K)          # Σ_b c_b · mean_k L_bk
 
         # ---- pass 2: backprop of the reweighted loss --------------------
@@ -348,7 +350,8 @@ def _dpsgd_r1f_sum(loss_fn, dp: DPConfig):
         _, pull, losses = jax.vjp(both, params, acc0, has_aux=True)
         zero_acc = jnp.zeros((B,), jnp.float32)
         _, nsq = pull((_view_seed(m, K), zero_acc))
-        c = clipping.clip_factors(nsq, C) * me
+        with jax.named_scope("dp_clip"):
+            c = clipping.clip_factors(nsq, C) * me
         grads, _ = pull((jax.lax.stop_gradient(_expand_rows(c, K)), zero_acc))
         grads = jax.tree.map(lambda g: g.astype(jnp.float32), grads)
         return grads, (losses, nsq)
@@ -477,8 +480,9 @@ def make_noisy_grad_fn(loss_fn: Callable, dp: DPConfig,
             C = dp.clip_norm if clip_norm is None else clip_norm
             denom = (float(expected_batch_size)
                      if expected_batch_size is not None else R // K)
-            grads = noise.add_noise(summed, key, dp.noise_multiplier,
-                                    C, denom)                  # lines 24/41
+            with jax.named_scope("dp_noise"):
+                grads = noise.add_noise(summed, key, dp.noise_multiplier,
+                                        C, denom)              # lines 24/41
             metrics = _metrics(losses, nsq, C, full_mask, mask_ex)
             if adaptive:
                 from repro.core import adaptive_clip

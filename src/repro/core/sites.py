@@ -254,14 +254,23 @@ def site_flops(kind: str, strategy: str, operand_shapes, gy_shape) -> float:
     return fn(operand_shapes, gy_shape)
 
 
+def norm_scope(kind: str, rule: str):
+    """``jax.named_scope`` of a site's norm work, ``dp_norm.<kind>.<rule>``
+    (the resolved rule), so its ops can be told apart in compiled HLO
+    metadata and in profiler traces."""
+    return jax.named_scope(f"dp_norm.{kind}.{rule}")
+
+
 def site_nsq(spec: SiteSpec, operands, gy) -> jax.Array:
     """Dispatch to the site's (resolved, possibly kernel-backed) norm rule."""
     site = get_site(spec.kind)
     shapes = tuple(getattr(o, "shape", ()) for o in operands)
     strat = resolve_strategy(spec.kind, spec.strategy, shapes, gy.shape)
-    if spec.use_kernels and strat in site.kernel_route:
-        return site.kernel_route[strat](spec, operands, gy)
-    return site.nsq_rules[strat](spec, operands, gy)
+    rule = (site.kernel_route[strat]
+            if spec.use_kernels and strat in site.kernel_route
+            else site.nsq_rules[strat])
+    with norm_scope(spec.kind, strat):
+        return rule(spec, operands, gy)
 
 
 # ---------------------------------------------------------------------------
@@ -287,7 +296,8 @@ def _site_call_bwd(spec, operands, cots):
     strat = resolve_strategy(spec.kind, spec.strategy, shapes, gy.shape)
     fused = site.fused_bwd.get(strat)
     if fused is not None:
-        grads, nsq = fused(spec, operands, gy)
+        with norm_scope(spec.kind, strat):
+            grads, nsq = fused(spec, operands, gy)
     else:
         grads = _operand_grads(site, spec, operands, gy)
         nsq = site_nsq(spec, operands, gy)

@@ -53,6 +53,7 @@ def clip_reduce(g: jax.Array, c: jax.Array, *, bb: int = 8, bn: int = 1024,
         out_specs=pl.BlockSpec((1, bn), lambda n, b: (0, n)),
         out_shape=jax.ShapeDtypeStruct((1, Np), F32),
         interpret=interpret,
+        name="clip_reduce_kernel",
     )(gp, cp)
     return out[0, :N]
 

@@ -130,6 +130,7 @@ def flash_attn_fwd(q: jax.Array, k: jax.Array, v: jax.Array, *,
         scratch_shapes=[_vmem((bq, 1), F32), _vmem((bq, 1), F32),
                         _vmem((bq, hdp), F32)],
         interpret=interpret,
+        name="flash_fwd_kernel",
     )(qp, kp, vp)
     return o[:, :T, :hd], lse[:, 0, :T]
 
@@ -262,6 +263,7 @@ def flash_attn_bwd(q: jax.Array, k: jax.Array, v: jax.Array, o: jax.Array,
         out_shape=[jax.ShapeDtypeStruct((BH, Sp, hdp), F32)] * 2,
         scratch_shapes=[_vmem((bk, hdp), F32)] * 2,
         interpret=interpret,
+        name="flash_bwd_kv_kernel",
     )(qp, dop, lsep, deltap, kp, vp)
 
     qspec2 = pl.BlockSpec((1, bq, hdp), lambda b, x, y: (b, x, 0))
@@ -275,6 +277,7 @@ def flash_attn_bwd(q: jax.Array, k: jax.Array, v: jax.Array, o: jax.Array,
         out_shape=jax.ShapeDtypeStruct((BH, Tp, hdp), F32),
         scratch_shapes=[_vmem((bq, hdp), F32)],
         interpret=interpret,
+        name="flash_bwd_q_kernel",
     )(qp, dop, lsep, deltap, kp, vp)
 
     dk = dkh[:, :S, :hd].reshape(BH // rep, rep, S, hd).sum(axis=1)

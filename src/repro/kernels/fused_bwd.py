@@ -154,6 +154,7 @@ def dense_bwd_norm(x: jax.Array, gy: jax.Array, w: jax.Array, *,
         ],
         scratch_shapes=[_vmem((bt, bi), F32), _vmem((bi, dop), F32)],
         interpret=interpret,
+        name="_fused_kernel",
     )(xp, gyp, wp)
     return gx[:, :T, :di], nsq[:, 0, 0]
 
@@ -183,6 +184,7 @@ def dense_dgrad(gy: jax.Array, w: jax.Array, *, bt: int = 128, bi: int = 128,
         out_specs=pl.BlockSpec((1, bt, bi), lambda b, i, t, j: (b, t, i)),
         out_shape=jax.ShapeDtypeStruct((BG, Tp, dip), gy.dtype),
         interpret=interpret,
+        name="dgrad_kernel",
         scratch_shapes=[_vmem((bt, bi), F32)],
     )(gyp, wp)
     return gx[:, :T, :di]

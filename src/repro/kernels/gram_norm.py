@@ -120,6 +120,7 @@ def gram_norm(x: jax.Array, gy: jax.Array, mask_ids: jax.Array | None = None,
         out_shape=jax.ShapeDtypeStruct((BG, 1, LANES), F32),
         scratch_shapes=[_vmem((bt, bt), F32), _vmem((bt, bt), F32)],
         interpret=interpret,
+        name="gram_norm_kernel",
     )(xp, xp, gyp, gyp, ids_col, ids_row)
     return out[:, 0, 0]
 

@@ -80,6 +80,7 @@ def pegrad_norm(x: jax.Array, gy: jax.Array, *, bt: int = 128, bi: int = 128,
         out_shape=jax.ShapeDtypeStruct((BG, 1, LANES), F32),
         scratch_shapes=[_vmem((bi, bj), F32)],
         interpret=interpret,
+        name="pegrad_norm_kernel",
     )(xp, gyp)
     return out[:, 0, 0]
 

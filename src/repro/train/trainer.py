@@ -32,6 +32,7 @@ from repro.core.algo import algo_is_private
 from repro.data import (augment_expand, batch_for, make_source,
                         poisson_batch_for, poisson_capacity)
 from repro.optim import make_optimizer
+from repro.train import telemetry
 from repro.train.checkpoint import CheckpointManager
 from repro.train.state import TrainState
 
@@ -90,8 +91,9 @@ def make_train_step(model, train_cfg: TrainConfig,
             from repro.dist.compress import compress_grads
             grads, new_err = compress_grads(grads,
                                             state.opt_state["grad_err"])
-        new_params, new_opt = opt.apply(grads, opt_state,
-                                        state.params, state.step)
+        with jax.named_scope("optim_update"):
+            new_params, new_opt = opt.apply(grads, opt_state,
+                                            state.params, state.step)
         if wrapped:
             new_opt = {"opt": new_opt}
             if compress:
@@ -332,16 +334,29 @@ class Trainer:
         Under ``dp.augmult = K > 1`` the sampled batch is then expanded to
         K deterministic views per example (capacity·K rows, b-major/
         k-minor; the mask broadcasts over K) — augmentation happens after
-        sampling, so the privacy unit stays the example."""
-        if self.sampling == "poisson":
-            batch = poisson_batch_for(self.source, self.model.arch,
-                                      self.shape, step,
-                                      capacity=self.capacity,
-                                      sample_rate=self.sample_rate)
-        else:
-            batch = batch_for(self.source, self.model.arch, self.shape, step)
-        return augment_expand(batch, self.cfg.dp.augmult,
-                              self.cfg.seed, step)
+        sampling, so the privacy unit stays the example.
+
+        Records the spans ``sample`` and (K > 1) ``augment`` and the
+        counters ``rows`` (physical rows) and ``real_rows`` (the mask's
+        sum) under ``step`` in ``telemetry``."""
+        with telemetry.span("sample", step):
+            if self.sampling == "poisson":
+                batch = poisson_batch_for(self.source, self.model.arch,
+                                          self.shape, step,
+                                          capacity=self.capacity,
+                                          sample_rate=self.sample_rate)
+            else:
+                batch = batch_for(self.source, self.model.arch, self.shape,
+                                  step)
+        if self.cfg.dp.augmult > 1:
+            with telemetry.span("augment", step):
+                batch = augment_expand(batch, self.cfg.dp.augmult,
+                                       self.cfg.seed, step)
+        rows = len(next(iter(batch.values())))
+        telemetry.count("rows", step, rows)
+        telemetry.count("real_rows", step, int(np.sum(batch["mask"]))
+                        if "mask" in batch else rows)
+        return batch
 
     # -- loop ---------------------------------------------------------------
     def run(self, state: TrainState, steps: Optional[int] = None,
@@ -354,73 +369,100 @@ class Trainer:
                 old_handlers[sig] = signal.signal(sig, self._handle_preempt)
         try:
             start = int(state.step)
+            # since: first step of the next history record; last: last step run
+            since = last = start
             for step in range(start, steps):
-                batch = self.shard_batch(self.make_batch(step))
-                key = jax.random.fold_in(jax.random.PRNGKey(cfg.seed), step)
-                t0 = time.perf_counter()
-                for attempt in range(3):   # transient-failure retry
-                    try:
-                        if (self.inject_failure_at == step
-                                and not self.inject_inside_jit
-                                and not self._injected):
-                            self._injected = True
-                            raise RuntimeError("injected transient failure")
-                        # keep `state` bound to the last good state until
-                        # the step has fully completed: with async dispatch
-                        # a failure inside the jitted computation can
-                        # surface at the block_until_ready, after step_fn
-                        # already returned poisoned arrays
-                        new_state, metrics = self.step_fn(state, batch, key)
-                        jax.block_until_ready(metrics["loss"])
-                        state = new_state
-                        break
-                    except RuntimeError as e:
-                        print(f"[trainer] step {step} attempt {attempt} "
-                              f"failed: {e}; retrying")
-                        if attempt == 2:
-                            raise
-                dt = time.perf_counter() - t0
-                self._watchdog(step, dt)
-                if (step + 1) % cfg.log_every == 0 or step == steps - 1:
-                    eps = self.accountant.epsilon_at(step + 1)
-                    rec = {k: float(v) for k, v in metrics.items()}
-                    rec.update(step=step, sec=dt, epsilon=eps,
-                               expected_batch=self.shape.global_batch)
-                    eps_str = f"eps {eps:.3f}"
-                    if len(self.accountant.mechanisms) > 1:
-                        # per-mechanism split (eps_grad / eps_clip / ...):
-                        # solo epsilons plus the composed total
-                        bd = self.accountant.epsilon_breakdown(step + 1)
-                        rec.update({k: float(v) for k, v in bd.items()})
-                        parts = " ".join(f"{k[4:]} {v:.3f}"
-                                         for k, v in bd.items()
-                                         if k != "eps_total")
-                        eps_str = f"eps {bd['eps_total']:.3f} ({parts})"
-                    self.history.append(rec)
-                    realized = ""
-                    if self.sampling == "poisson":
-                        realized = (f"B {rec['realized_batch']:.0f}"
-                                    f"/{self.shape.global_batch} ")
-                    print(f"[trainer] step {step:5d} "
-                          f"loss {rec['loss']:.4f} {eps_str} "
-                          f"{realized}({dt*1e3:.0f} ms)")
-                if (step + 1) % cfg.ckpt_every == 0 or step == steps - 1 \
-                        or self._preempted:
-                    self.ckpt.save(state, step + 1)
+                last = step
+                telemetry.start(step)
+                with jax.profiler.StepTraceAnnotation("train", step_num=step):
+                    b = self.make_batch(step)
+                    with telemetry.span("transfer", step):
+                        batch = self.shard_batch(b)
+                    key = jax.random.fold_in(jax.random.PRNGKey(cfg.seed),
+                                             step)
+                    t0 = time.perf_counter()
+                    for attempt in range(3):   # transient-failure retry
+                        try:
+                            if (self.inject_failure_at == step
+                                    and not self.inject_inside_jit
+                                    and not self._injected):
+                                self._injected = True
+                                raise RuntimeError(
+                                    "injected transient failure")
+                            # keep `state` bound to the last good state
+                            # until the step has fully completed: with async
+                            # dispatch a failure inside the jitted
+                            # computation can surface at the
+                            # block_until_ready, after step_fn already
+                            # returned poisoned arrays
+                            with telemetry.span("dispatch", step):
+                                new_state, metrics = self.step_fn(
+                                    state, batch, key)
+                            with telemetry.span("device_wait", step):
+                                jax.block_until_ready(metrics["loss"])
+                            state = new_state
+                            break
+                        except RuntimeError as e:
+                            telemetry.count("retries", step)
+                            print(f"[trainer] step {step} attempt {attempt} "
+                                  f"failed: {e}; retrying")
+                            if attempt == 2:
+                                raise
+                    dt = time.perf_counter() - t0
+                    self._watchdog(step, dt)
+                    if (step + 1) % cfg.log_every == 0 or step == steps - 1:
+                        with telemetry.span("log", step):
+                            self._log(step, dt, metrics, since)
+                        since = step + 1
+                    if (step + 1) % cfg.ckpt_every == 0 \
+                            or step == steps - 1 or self._preempted:
+                        with telemetry.span("checkpoint", step):
+                            self.ckpt.save(state, step + 1)
                 if self._preempted:
                     print(f"[trainer] preempted at step {step}; "
                           f"checkpoint saved, exiting")
                     break
-            self.ckpt.wait()
+            with telemetry.span("checkpoint", last):
+                self.ckpt.wait()
             return state
         finally:
             for sig, h in old_handlers.items():
                 signal.signal(sig, h)
+
+    def _log(self, step: int, dt: float, metrics, since: int) -> None:
+        """Append the history record of ``step`` and print its line.  The
+        record carries the step's metrics, the privacy spent, and from
+        ``telemetry`` the mean ms per step of each span (``<span>_ms``)
+        and the counters' sums over steps ``since`` .. ``step``."""
+        eps = self.accountant.epsilon_at(step + 1)
+        rec = {k: float(v) for k, v in metrics.items()}
+        rec.update(step=step, sec=dt, epsilon=eps,
+                   expected_batch=self.shape.global_batch)
+        rec.update(telemetry.RECORDER.summary(range(since, step + 1)))
+        eps_str = f"eps {eps:.3f}"
+        if len(self.accountant.mechanisms) > 1:
+            # per-mechanism split (eps_grad / eps_clip / ...):
+            # solo epsilons plus the composed total
+            bd = self.accountant.epsilon_breakdown(step + 1)
+            rec.update({k: float(v) for k, v in bd.items()})
+            parts = " ".join(f"{k[4:]} {v:.3f}" for k, v in bd.items()
+                             if k != "eps_total")
+            eps_str = f"eps {bd['eps_total']:.3f} ({parts})"
+        self.history.append(rec)
+        realized = ""
+        if self.sampling == "poisson":
+            realized = (f"B {rec['realized_batch']:.0f}"
+                        f"/{self.shape.global_batch} ")
+        host = sum(rec.get(f"{n}_ms") or 0.0
+                   for n in ("sample", "augment", "transfer"))
+        print(f"[trainer] step {step:5d} loss {rec['loss']:.4f} {eps_str} "
+              f"{realized}host {host:.0f} ms ({dt*1e3:.0f} ms)")
 
     def _watchdog(self, step: int, dt: float) -> None:
         self._step_times.append(dt)
         hist = self._step_times[-50:]
         med = float(np.median(hist))
         if len(hist) >= 5 and dt > self.cfg.watchdog_factor * med:
+            telemetry.count("stragglers", step)
             print(f"[trainer] WATCHDOG straggler: step {step} took "
                   f"{dt:.2f}s (median {med:.2f}s)")

@@ -16,6 +16,7 @@ this file loads libtpu.
 """
 import functools
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -76,19 +77,31 @@ def one_chip(topo):
     return SingleDeviceSharding(topo.devices[0])
 
 
-def _compile(fn, one_chip, *shapes):
+def _compile(fn, one_chip, *shapes) -> str:
+    """The compiled module's HLO text, which must hold a Mosaic kernel."""
     args = [jax.ShapeDtypeStruct(s, dt, sharding=one_chip)
             for s, dt in shapes]
     hlo = jax.jit(fn).lower(*args).compile().as_text()
     assert "tpu_custom_call" in hlo
+    return hlo
+
+
+def _kernel_names(hlo: str) -> list:
+    """Instruction names of the ``tpu_custom_call``s, without the ``.N``
+    suffix XLA adds: the ``name`` each ``pallas_call`` gives its kernel,
+    which device traces then show as the op's name."""
+    return [re.match(r"\s*(?:ROOT\s+)?%?([\w.\-]+?)(?:\.\d+)?\s*=", line)
+            .group(1) for line in hlo.splitlines()
+            if 'custom_call_target="tpu_custom_call"' in line]
 
 
 @pytest.mark.parametrize("arch", ARCHS)
 def test_pegrad_norm_compiles(arch, one_chip):
     w = _widths(arch)
-    _compile(functools.partial(_pn.pegrad_norm, interpret=False), one_chip,
-             ((w["rows"], w["T"], w["d"]), BF16),
-             ((w["rows"], w["T"], w["ff"]), BF16))
+    hlo = _compile(functools.partial(_pn.pegrad_norm, interpret=False),
+                   one_chip, ((w["rows"], w["T"], w["d"]), BF16),
+                   ((w["rows"], w["T"], w["ff"]), BF16))
+    assert _kernel_names(hlo) == ["pegrad_norm_kernel"]
 
 
 @pytest.mark.parametrize("arch", ARCHS)
@@ -96,22 +109,25 @@ def test_gram_norm_compiles(arch, one_chip):
     """The dense ghost norm, and the id-masked embedding rule (the
     ``segment_sum`` kernel route: x = gy, square=False)."""
     w = _widths(arch)
-    _compile(functools.partial(_gn.gram_norm, interpret=False), one_chip,
-             ((w["rows"], w["T"], w["d"]), BF16),
-             ((w["rows"], w["T"], w["ff"]), BF16))
-    _compile(lambda gy, ids: _gn.gram_norm(gy, gy, ids, square=False,
-                                           interpret=False), one_chip,
-             ((w["rows"], w["T"], w["d"]), BF16),
-             ((w["rows"], w["T"]), jnp.int32))
+    hlo = _compile(functools.partial(_gn.gram_norm, interpret=False),
+                   one_chip, ((w["rows"], w["T"], w["d"]), BF16),
+                   ((w["rows"], w["T"], w["ff"]), BF16))
+    assert _kernel_names(hlo) == ["gram_norm_kernel"]
+    hlo = _compile(lambda gy, ids: _gn.gram_norm(gy, gy, ids, square=False,
+                                                 interpret=False), one_chip,
+                   ((w["rows"], w["T"], w["d"]), BF16),
+                   ((w["rows"], w["T"]), jnp.int32))
+    assert _kernel_names(hlo) == ["gram_norm_kernel"]
 
 
 @pytest.mark.parametrize("arch", ARCHS)
 def test_clip_reduce_compiles(arch, one_chip):
     """Per-example flattened grads of one FFN weight, (B, d·d_ff)."""
     w = _widths(arch)
-    _compile(functools.partial(_cr.clip_reduce, interpret=False), one_chip,
-             ((w["rows"], w["d"] * w["ff"]), jnp.float32),
-             ((w["rows"],), jnp.float32))
+    hlo = _compile(functools.partial(_cr.clip_reduce, interpret=False),
+                   one_chip, ((w["rows"], w["d"] * w["ff"]), jnp.float32),
+                   ((w["rows"],), jnp.float32))
+    assert _kernel_names(hlo) == ["clip_reduce_kernel"]
 
 
 @pytest.mark.parametrize("arch", ARCHS)
@@ -119,18 +135,21 @@ def test_clip_reduce_compiles(arch, one_chip):
 def test_dense_bwd_norm_compiles(arch, site, one_chip):
     w = _widths(arch)
     di, do = (w["d"], w["ff"]) if site == "ffn-up" else (w["ff"], w["d"])
-    _compile(functools.partial(_fb.dense_bwd_norm, interpret=False), one_chip,
-             ((w["rows"], w["T"], di), BF16),
-             ((w["rows"], w["T"], do), BF16),
-             ((1, di, do), BF16))
+    hlo = _compile(functools.partial(_fb.dense_bwd_norm, interpret=False),
+                   one_chip, ((w["rows"], w["T"], di), BF16),
+                   ((w["rows"], w["T"], do), BF16), ((1, di, do), BF16))
+    # the benchmark's roofline.dense_bwd_norm and norm_kernel_share read
+    # this kernel by this exact name
+    assert _kernel_names(hlo) == ["_fused_kernel"]
 
 
 @pytest.mark.parametrize("arch", ARCHS)
 def test_dense_dgrad_compiles(arch, one_chip):
     w = _widths(arch)
-    _compile(functools.partial(_fb.dense_dgrad, interpret=False), one_chip,
-             ((w["rows"], w["T"], w["ff"]), BF16),
-             ((1, w["d"], w["ff"]), BF16))
+    hlo = _compile(functools.partial(_fb.dense_dgrad, interpret=False),
+                   one_chip, ((w["rows"], w["T"], w["ff"]), BF16),
+                   ((1, w["d"], w["ff"]), BF16))
+    assert _kernel_names(hlo) == ["dgrad_kernel"]
 
 
 def _flash_shapes(w):
@@ -143,8 +162,10 @@ def test_flash_fwd_compiles(arch, one_chip):
     w = _widths(arch)
     q, kv = _flash_shapes(w)
     rep = w["heads"] // w["kv"]
-    _compile(functools.partial(_fa.flash_attn_fwd, causal=w["causal"], rep=rep,
-                               interpret=False), one_chip, q, kv, kv)
+    hlo = _compile(functools.partial(_fa.flash_attn_fwd, causal=w["causal"],
+                                     rep=rep, interpret=False),
+                   one_chip, q, kv, kv)
+    assert _kernel_names(hlo) == ["flash_fwd_kernel"]
 
 
 @pytest.mark.parametrize("arch", ARCHS)
@@ -153,6 +174,8 @@ def test_flash_bwd_compiles(arch, one_chip):
     q, kv = _flash_shapes(w)
     rep = w["heads"] // w["kv"]
     lse = ((q[0][0], w["T"]), jnp.float32)
-    _compile(functools.partial(_fa.flash_attn_bwd, causal=w["causal"], rep=rep,
-                               interpret=False), one_chip,
-             q, kv, kv, q, lse, q)
+    hlo = _compile(functools.partial(_fa.flash_attn_bwd, causal=w["causal"],
+                                     rep=rep, interpret=False), one_chip,
+                   q, kv, kv, q, lse, q)
+    assert sorted(_kernel_names(hlo)) == ["flash_bwd_kv_kernel",
+                                          "flash_bwd_q_kernel"]
