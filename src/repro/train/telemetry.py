@@ -9,18 +9,20 @@ read it by step number.
   so that, while a profiler trace runs, the span sits on the profiler's
   clock beside the device ops.
 * ``count(name, step, n)`` adds ``n`` to a counter of the step.
-* ``start(step)`` gives the step a fresh record (the loop calls it at the
-  top of each iteration, so a second run over the same step numbers does
-  not add to the first run's records).
+* ``start(step)`` gives the step a fresh record (the loop calls it before
+  the step's batch is built, so a second run over the same step numbers
+  does not add to the first run's records).
 
 Only the last ``MAX_STEPS`` steps are kept.  Every profiler event is named
 under ``train.``, so none falls under ``bench.``, the prefix of the
 benchmark harness's own spans.  Nothing here reads a device value or waits
-for the device.  The loop writes from one thread.
+for the device.  Two threads write: the loop, and the prefetch worker that
+builds the next step's batch meanwhile; a lock guards every write and read.
 """
 from __future__ import annotations
 
 import contextlib
+import threading
 import time
 from collections import OrderedDict
 from typing import Iterable, Optional
@@ -38,8 +40,11 @@ class Recorder:
     def __init__(self, max_steps: int = MAX_STEPS):
         self.max_steps = max_steps
         self._steps: OrderedDict = OrderedDict()   # step -> (spans, counts)
+        self._lock = threading.Lock()
 
     def _record(self, step: int):
+        """The step's (spans, counts), made if absent; the caller holds
+        the lock."""
         rec = self._steps.get(step)
         if rec is None:
             rec = self._steps[step] = ({}, {})
@@ -48,8 +53,9 @@ class Recorder:
         return rec
 
     def start(self, step: int) -> None:
-        self._steps.pop(step, None)
-        self._record(step)
+        with self._lock:
+            self._steps.pop(step, None)
+            self._record(step)
 
     @contextlib.contextmanager
     def span(self, name: str, step: int):
@@ -58,26 +64,36 @@ class Recorder:
             try:
                 yield
             finally:
-                spans = self._record(step)[0]
-                spans[name] = spans.get(name, 0.0) + time.perf_counter() - t0
+                dt = time.perf_counter() - t0
+                with self._lock:
+                    spans = self._record(step)[0]
+                    spans[name] = spans.get(name, 0.0) + dt
 
     def count(self, name: str, step: int, n: int = 1) -> None:
-        counts = self._record(step)[1]
-        counts[name] = counts.get(name, 0) + n
+        with self._lock:
+            counts = self._record(step)[1]
+            counts[name] = counts.get(name, 0) + n
 
     def steps(self) -> list:
-        return list(self._steps)
+        with self._lock:
+            return list(self._steps)
 
     def spans(self, step: int) -> dict:
         """{span: seconds} of one step ({} if not recorded)."""
-        return dict(self._steps.get(step, ({}, {}))[0])
+        with self._lock:
+            return dict(self._steps.get(step, ({}, {}))[0])
 
     def counts(self, step: int) -> dict:
-        return dict(self._steps.get(step, ({}, {}))[1])
+        with self._lock:
+            return dict(self._steps.get(step, ({}, {}))[1])
 
     def mean_ms(self, name: str, steps: Iterable[int]) -> Optional[float]:
         """Milliseconds of span ``name`` per recorded step among ``steps``;
         None if no such step recorded it."""
+        with self._lock:
+            return self._mean_ms(name, steps)
+
+    def _mean_ms(self, name: str, steps: Iterable[int]) -> Optional[float]:
         recs = [self._steps[s][0] for s in steps if s in self._steps]
         if not any(name in r for r in recs):
             return None
@@ -86,16 +102,18 @@ class Recorder:
     def summary(self, steps: Iterable[int]) -> dict:
         """``<span>_ms`` (mean per recorded step) for every span, and the
         sum of every counter, over ``steps``."""
-        steps = [s for s in steps if s in self._steps]
-        names = {n for s in steps for n in self._steps[s][0]}
-        out = {f"{n}_ms": self.mean_ms(n, steps) for n in sorted(names)}
-        for s in steps:
-            for n, v in self._steps[s][1].items():
-                out[n] = out.get(n, 0) + v
+        with self._lock:
+            steps = [s for s in steps if s in self._steps]
+            names = {n for s in steps for n in self._steps[s][0]}
+            out = {f"{n}_ms": self._mean_ms(n, steps) for n in sorted(names)}
+            for s in steps:
+                for n, v in self._steps[s][1].items():
+                    out[n] = out.get(n, 0) + v
         return out
 
     def clear(self) -> None:
-        self._steps.clear()
+        with self._lock:
+            self._steps.clear()
 
 
 RECORDER = Recorder()

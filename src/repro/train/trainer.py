@@ -12,9 +12,20 @@ Fault-tolerance model (1000+-node design, DESIGN.md §5):
   ``inject_inside_jit`` — leave ``state`` intact for the retry.
 * Straggler watchdog: any step slower than ``watchdog_factor`` x the median
   is logged with its step index (on real fleets this feeds the scheduler).
+
+Input prefetch: inside one ``run`` call, once step s is dispatched, the
+host build of step s+1 (sampling, augmult; numpy only) goes to a single
+worker thread while the loop waits on the device.  The loop still calls
+``make_batch`` once per step, on its own thread, and gets the built batch
+from it.  The transfer to the device stays on the loop thread, after the
+previous step's wait, so one batch at a time is on the device.  The first
+step of each call is built in place, no build starts for a step at or past
+the call's end, and no worker outlives the call.  Batches are (seed,
+step)-keyed, so building one step early changes no batch.
 """
 from __future__ import annotations
 
+import concurrent.futures
 import dataclasses
 import math
 import signal
@@ -228,6 +239,7 @@ class Trainer:
                 _aclip.mechanism(train_cfg.dp, self.sample_rate))
         self.shard_batch = shard_batch or (lambda b: jax.tree.map(jnp.asarray, b))
         self._preempted = False
+        self._prefetch = None     # (step, Future) of the worker's build
         self._step_times: list = []
         self.history: list = []
 
@@ -329,12 +341,31 @@ class Trainer:
 
     def make_batch(self, step: int):
         """The step's (seed, step)-keyed batch under the configured
-        sampling mode.  Poisson batches carry a ``"mask"`` validity leaf
-        and a step-invariant physical *example* count (``self.capacity``).
-        Under ``dp.augmult = K > 1`` the sampled batch is then expanded to
-        K deterministic views per example (capacity·K rows, b-major/
-        k-minor; the mask broadcasts over K) — augmentation happens after
-        sampling, so the privacy unit stays the example.
+        sampling mode (``_build_batch``).  Inside ``run`` the batch is
+        usually built already, on the prefetch worker; this returns it when
+        one is pending for ``step`` (counter ``prefetched``), re-raising
+        the builder's exception if it failed, and otherwise builds the
+        batch here.  The span ``batch_wait`` is the time the caller blocks
+        on the batch."""
+        with telemetry.span("batch_wait", step):
+            pending = self._prefetch
+            if pending is not None and pending[0] == step:
+                self._prefetch = None
+                batch = pending[1].result()
+                telemetry.count("prefetched", step)
+            else:
+                batch = self._build_batch(step)
+        return batch
+
+    def _build_batch(self, step: int):
+        """Build the step's batch.  Poisson batches carry a ``"mask"``
+        validity leaf and a step-invariant physical *example* count
+        (``self.capacity``).  Under ``dp.augmult = K > 1`` the sampled
+        batch is then expanded to K deterministic views per example
+        (capacity·K rows, b-major/k-minor; the mask broadcasts over K) —
+        augmentation happens after sampling, so the privacy unit stays the
+        example.  Host (numpy) work only, so it may run on the prefetch
+        worker.
 
         Records the spans ``sample`` and (K > 1) ``augment`` and the
         counters ``rows`` (physical rows) and ``real_rows`` (the mask's
@@ -367,13 +398,15 @@ class Trainer:
         if install_signals:
             for sig in (signal.SIGTERM, signal.SIGINT):
                 old_handlers[sig] = signal.signal(sig, self._handle_preempt)
+        pool = None   # the prefetch worker, started at the first hand-over
         try:
             start = int(state.step)
             # since: first step of the next history record; last: last step run
             since = last = start
             for step in range(start, steps):
                 last = step
-                telemetry.start(step)
+                if self._prefetch is None:  # else started at hand-over
+                    telemetry.start(step)
                 with jax.profiler.StepTraceAnnotation("train", step_num=step):
                     b = self.make_batch(step)
                     with telemetry.span("transfer", step):
@@ -398,6 +431,16 @@ class Trainer:
                             with telemetry.span("dispatch", step):
                                 new_state, metrics = self.step_fn(
                                     state, batch, key)
+                            nxt = step + 1
+                            if nxt < steps and self._prefetch is None \
+                                    and not self._preempted:
+                                # build step s+1 while the device runs step s
+                                pool = pool or \
+                                    concurrent.futures.ThreadPoolExecutor(
+                                        1, thread_name_prefix="prefetch")
+                                telemetry.start(nxt)
+                                self._prefetch = (nxt, pool.submit(
+                                    self._build_batch, nxt))
                             with telemetry.span("device_wait", step):
                                 jax.block_until_ready(metrics["loss"])
                             state = new_state
@@ -426,6 +469,10 @@ class Trainer:
                 self.ckpt.wait()
             return state
         finally:
+            if pool is not None:
+                # join the outstanding build (or cancel it, not yet begun)
+                pool.shutdown(wait=True, cancel_futures=True)
+            self._prefetch = None
             for sig, h in old_handlers.items():
                 signal.signal(sig, h)
 
@@ -453,8 +500,10 @@ class Trainer:
         if self.sampling == "poisson":
             realized = (f"B {rec['realized_batch']:.0f}"
                         f"/{self.shape.global_batch} ")
+        # host time the loop blocks on before the step: the build it waits
+        # for (all of it when built in place) and the transfer
         host = sum(rec.get(f"{n}_ms") or 0.0
-                   for n in ("sample", "augment", "transfer"))
+                   for n in ("batch_wait", "transfer"))
         print(f"[trainer] step {step:5d} loss {rec['loss']:.4f} {eps_str} "
               f"{realized}host {host:.0f} ms ({dt*1e3:.0f} ms)")
 

@@ -14,8 +14,8 @@ from repro.train.trainer import make_train_step
 from helpers import tiny_model
 
 SHAPE = ShapeConfig("t", 4, 8, "train")
-LOOP_SPANS = {"sample", "transfer", "dispatch", "device_wait", "log",
-              "checkpoint"}
+LOOP_SPANS = {"batch_wait", "sample", "transfer", "dispatch", "device_wait",
+              "log", "checkpoint"}
 
 
 def _trainer(tmp_path, K, steps=3, log_every=1):
