@@ -29,13 +29,15 @@ ROOT = Path(__file__).resolve().parents[1]
 
 def readings(cell, seed: int, variants=("control", "half", "halfsum")
              ) -> dict:
-    from bench import check, harness, reference, weights as W
+    """Each variant's compared numbers on one seed, on the cell's chips."""
+    import jax
+    from bench import check, harness, reference
     mix = cell.mix
     want = [cell.cfg.batch(cell.conf, mix, seed, s)
             for s in range(harness.FIRST_STEPS)]
-    params0 = W.make(cell.cfg.layout(cell.conf, mix), seed)
+    draw = harness.reference_weights(cell, seed, jax.devices()[:cell.chips])
     run = lambda mm, fault=None: reference.run(
-        cell.ref.row_losses, cell.conf, mix, params0, want, seed, mm,
+        cell.ref.row_losses, cell.conf, mix, draw, want, seed, mm,
         block=mix["reference_block"], fault=fault)
     ref = run(reference.Mm())
     out = {}

@@ -28,6 +28,7 @@ import contextlib
 import gc
 import importlib.util
 import json
+import math
 import shutil
 import sys
 import tempfile
@@ -61,6 +62,7 @@ class Cell:
     mix: dict
     limits: dict
     chips: int
+    mesh: dict             # axis -> size, in the order written
     end_to_end: list
     per_layer: list
 
@@ -81,13 +83,27 @@ def load_cell(spec: dict, workload: str, overrides: dict | None = None
     limits = json.loads(lim_path.read_text()) if lim_path.exists() else {}
     conf.update((overrides or {}).get("conf", {}))
     mix.update((overrides or {}).get("mix", {}))
+    chips = int(w["chips"])
     applies = lambda m: workload in m.get("workloads", [workload])
     return Cell(workload, conf,
                 load_module(cdir / f"{w['config']}.py", "bench_cfg"),
                 load_module(cdir / f"{w['config']}.ref.py", "bench_ref"),
-                mix, limits, int(w["chips"]),
+                mix, limits, chips, mesh_of(mix, chips),
                 [m for m in spec["end_to_end"] if applies(m)],
                 [m for m in spec["per_layer"] if applies(m)])
+
+
+def mesh_of(mix: dict, chips: int) -> dict:
+    """The cell's mesh: the mix's ``mesh`` ({axis: size}, axes in the
+    order written), or every chip on ``data``.  Its devices have to be
+    the cell's chips."""
+    mesh = {str(a): int(n) for a, n in
+            (mix.get("mesh") or {"data": chips}).items()}
+    if math.prod(mesh.values()) != chips:
+        raise ValueError(f"the mix's mesh {mesh} spans "
+                         f"{math.prod(mesh.values())} devices; the cell "
+                         f"asks for {chips} chip(s)")
+    return mesh
 
 
 # ---------------------------------------------------------------------------
@@ -212,20 +228,14 @@ class CompileWatch:
 # the program
 # ---------------------------------------------------------------------------
 
-def build_program(cell: Cell, seed: int, ckpt_dir: str, devices):
-    """Model, trainer, mesh contexts and the initial state, as
-    ``repro.launch.train`` builds them, with the benchmark's weights."""
-    import jax
+def make_trainer(cell: Cell, seed: int, ckpt_dir: str, mesh):
+    """The program's model and ``Trainer`` for the cell on ``mesh``, as
+    ``repro.launch.train`` builds them; no state yet."""
     from repro.configs.base import (DPConfig, MemConfig, OptimConfig,
                                     ShapeConfig, TrainConfig)
-    from repro.dist import batch_shardings, runtime, state_shardings
-    from repro.dist.sharding import batch_axis_width, batch_pspec
-    from repro.launch.mesh import make_mesh
+    from repro.dist.sharding import batch_axis_width
     from repro.models import build_model_for
     from repro.train import Trainer
-    from repro.train.state import TrainState
-    from repro.train.trainer import make_opt_init
-    from bench import weights as W
 
     mix, conf = cell.mix, cell.conf
     arch = cell.cfg.arch(conf)
@@ -248,9 +258,29 @@ def build_program(cell: Cell, seed: int, ckpt_dir: str, devices):
     model = build_model_for(arch, param_dtype=cfg.param_dtype,
                             compute_dtype=cfg.compute_dtype, remat=cfg.remat,
                             pp_stages=1, pp_microbatches=0)
-    mesh = make_mesh([len(devices)], ["data"])
     trainer = Trainer(model, cfg, shape, batch_multiple=batch_axis_width(mesh))
     trainer.ckpt = NullCheckpoints()
+    return trainer
+
+
+def build_program(cell: Cell, seed: int, ckpt_dir: str):
+    """Model, trainer, the initial state, a function that draws its
+    weights again, and the mesh contexts, as ``repro.launch.train`` builds
+    them, on the cell's mesh, with the benchmark's weights drawn into the
+    state's shardings.  The harness keeps no copy of the first weights
+    while the program runs: the change is taken from a second draw."""
+    import jax
+    from repro.dist import batch_shardings, runtime, state_shardings
+    from repro.dist.sharding import batch_pspec
+    from repro.launch.mesh import make_mesh
+    from repro.train.state import TrainState
+    from repro.train.trainer import make_opt_init
+    from bench import weights as W
+
+    mix, conf = cell.mix, cell.conf
+    mesh = make_mesh(list(cell.mesh.values()), list(cell.mesh))
+    trainer = make_trainer(cell, seed, ckpt_dir, mesh)
+    model, cfg = trainer.model, trainer.cfg
     phys = trainer.capacity
 
     layout = cell.cfg.layout(conf, mix)
@@ -270,11 +300,13 @@ def build_program(cell: Cell, seed: int, ckpt_dir: str, devices):
         return jax.tree.map(lambda a, s: jax.device_put(a, s), b, sh)
 
     trainer.shard_batch = shard_batch
-    params0 = W.make(layout, seed)
-    state = TrainState.create(params0, make_opt_init(cfg, trainer.opt)(params0))
     sh = state_shardings(mesh, model, trainer.abstract_state(), zero1=cfg.zero1)
+    params0 = W.make(layout, seed, sh.params)
+    opt0 = jax.jit(make_opt_init(cfg, trainer.opt),
+                   out_shardings=sh.opt_state)(params0)
+    state = TrainState.create(params0, opt0)
     state = jax.tree.map(lambda x, s: jax.device_put(x, s), state, sh)
-    return trainer, state, state.params, ctx
+    return trainer, state, lambda: W.make(layout, seed, sh.params), ctx
 
 
 def _grad_from_opt(mix: dict, opt_state):
@@ -310,8 +342,7 @@ def run_cell(spec: dict, workload: str, seed: int, seconds: float,
     ckpt_dir = tempfile.mkdtemp(prefix="bench-ckpt-")
     trace_dir = tempfile.mkdtemp(prefix="bench-trace-") if trace else None
     try:
-        trainer, state, params0, ctx = build_program(cell, seed, ckpt_dir,
-                                                     devices)
+        trainer, state, weights0, ctx = build_program(cell, seed, ckpt_dir)
         with ctx:
             if plant is not None:
                 plant(trainer)
@@ -325,12 +356,12 @@ def run_cell(spec: dict, workload: str, seed: int, seconds: float,
                         reference.noise_terms(cell.mix, 1))
             prog["change_norms"] = reference.noise_free_norms(
                 state.params, seed,
-                reference.noise_terms(cell.mix, FIRST_STEPS), base=params0)
+                reference.noise_terms(cell.mix, FIRST_STEPS),
+                base=weights0())
             hist = trainer.history[-FIRST_STEPS:]
             for k, hk in (("loss", "loss"), ("norm_mean", "grad_norm_mean"),
                           ("norm_max", "grad_norm_max")):
                 prog[k] = [h.get(hk, np.nan) for h in hist]
-            del params0
             s0 = FIRST_STEPS + WARM_STEPS
             state = trainer.run(state, s0, install_signals=False)
             period = float(np.median([spans.iter_t[s + 1] - spans.iter_t[s]
@@ -380,10 +411,14 @@ def run_cell(spec: dict, workload: str, seed: int, seconds: float,
             peak=peak, chips=len(devices), trace=None)
         if trace:
             ctxm.trace = T.reduce(T.find_xplane(trace_dir), hlo)
+            print(f"trace: busy {ctxm.trace.busy_s!r} s, collectives "
+                  f"{ctxm.trace.collective_s!r} s, exposed "
+                  f"{ctxm.trace.exposed_collective_s!r} s of "
+                  f"{ctxm.trace.window_s!r} s", file=sys.stderr)
 
         # -- correctness: the reference, after the program's state is freed
         t_ref = time.perf_counter()
-        nums = check_against_reference(cell, seed, prog, batches)
+        nums = check_against_reference(cell, seed, prog, batches, devices)
         print(f"reference: {time.perf_counter() - t_ref:.1f} s",
               file=sys.stderr)
         nums["window_compiles"] = float(compiled_in_window)
@@ -452,17 +487,26 @@ def _traced(trainer, spans: Spans, trace_dir: str, first: int) -> int:
     return last
 
 
+def reference_weights(cell: Cell, seed: int, devices):
+    """``draw()``: the benchmark's weights, where the reference keeps
+    them on the cell's ``devices`` (``reference.shardings``)."""
+    from bench import reference, weights as W
+    layout = cell.cfg.layout(cell.conf, cell.mix)
+    sh = reference.shardings(W.abstract(layout), devices)
+    return lambda: W.make(layout, seed, sh)
+
+
 def check_against_reference(cell: Cell, seed: int, prog: dict,
-                            batches: dict, mm=None) -> dict:
+                            batches: dict, devices) -> dict:
     """Rebuild the first batches, run the reference over them from the
-    benchmark's weights, and return the compared numbers."""
-    from bench import check, reference, weights as W
+    benchmark's weights on the cell's ``devices``, and return the
+    compared numbers."""
+    from bench import check, reference
     want = [cell.cfg.batch(cell.conf, cell.mix, seed, s)
             for s in range(FIRST_STEPS)]
     got = [batches.get(s, {}) for s in range(FIRST_STEPS)]
     prog = dict(prog, batch_mismatch=check.batch_mismatch(got, want))
-    params0 = W.make(cell.cfg.layout(cell.conf, cell.mix), seed)
-    ref = reference.run(cell.ref.row_losses, cell.conf, cell.mix, params0,
-                        want, seed, mm or reference.Mm(),
-                        block=cell.mix["reference_block"])
+    ref = reference.run(cell.ref.row_losses, cell.conf, cell.mix,
+                        reference_weights(cell, seed, devices), want, seed,
+                        reference.Mm(), block=cell.mix["reference_block"])
     return check.numbers(prog, ref, cell.mix["algo"] != "sgd")

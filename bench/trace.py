@@ -15,7 +15,13 @@ From the ``.xplane.pb`` file of a traced window:
   shapes;
 * idle gaps: the stretches of the window in which no operation ran on a
   device, each tagged with the host span (``bench.<name>``) that overlaps
-  it most.
+  it most;
+* collective time: the union of the intervals of the collective
+  operations on each device plane (``all-gather``, ``reduce-scatter``,
+  ``all-reduce``, ``collective-permute``, ``all-to-all`` and their
+  ``-start``/``-done`` halves, by HLO instruction name), and the part of
+  it that no other operation on the same plane covers (exposed), both
+  averaged over devices.  A one-chip trace has none and reads 0.
 """
 from __future__ import annotations
 
@@ -62,6 +68,40 @@ def gaps(merged, lo, hi):
     if t < hi:
         out.append((t, hi))
     return out
+
+
+def covered(a, b) -> float:
+    """Length of the intersection of two merged, sorted interval lists."""
+    total, i, j = 0.0, 0, 0
+    while i < len(a) and j < len(b):
+        lo, hi = max(a[i][0], b[j][0]), min(a[i][1], b[j][1])
+        if hi > lo:
+            total += hi - lo
+        if a[i][1] < b[j][1]:
+            i += 1
+        else:
+            j += 1
+    return total
+
+
+COLLECTIVES = ("all-gather", "reduce-scatter", "all-reduce",
+               "collective-permute", "all-to-all")
+
+
+def is_collective(name: str) -> bool:
+    """An HLO instruction name (``all-reduce-start.3``) of a collective,
+    or of one of its asynchronous halves."""
+    return re.sub(r"(\.\d+)+$", "", name).startswith(COLLECTIVES)
+
+
+def collective_times(events) -> tuple[float, float]:
+    """(collective, exposed) time of one device plane's ``(op name, start,
+    end)`` events: the union of the collectives' intervals, and the part
+    of it that no other operation's interval covers."""
+    coll = union([(s, e) for n, s, e in events if is_collective(n)])
+    other = union([(s, e) for n, s, e in events if not is_collective(n)])
+    total = sum(e - s for s, e in coll)
+    return total, total - covered(coll, other)
 
 
 def tag(gap, spans):
@@ -125,6 +165,8 @@ class Reduction:
     gap_list: list                 # (host span, seconds)
     n_devices: int
     kernels: dict                  # custom_calls() of the compiled step
+    collective_s: float = 0.0      # averaged over device planes
+    exposed_collective_s: float = 0.0
 
     def top_ops(self, n: int):
         return [[k, v] for k, v in
@@ -191,8 +233,9 @@ def reduce(path, hlo_text: str | None = None) -> Reduction:
     if not devs:
         raise ValueError("the trace has no TPU device plane")
     busy, ops, calls, gap_list = 0.0, {}, [], []
+    coll, exposed = 0.0, 0.0
     for p in devs:
-        iv = []
+        iv, named = [], []
         for e in _op_line(p).events:
             s, t = e.start_ns, e.start_ns + e.duration_ns
             c = clip([(s, t)], lo, hi)
@@ -205,10 +248,16 @@ def reduce(path, hlo_text: str | None = None) -> Reduction:
             name = op_name(e.name)
             ops[name] = ops.get(name, 0.0) + d
             calls.append((name, d))
+            named.append((name,) + c[0])
         merged = union(iv)
         busy += sum(e - s for s, e in merged) * 1e-9
         gap_list += [(tag(g, spans), (g[1] - g[0]) * 1e-9)
                      for g in gaps(merged, lo, hi)]
-    return Reduction(window_s=(hi - lo) * 1e-9, busy_s=busy / len(devs),
+        c_ns, x_ns = collective_times(named)
+        coll += c_ns * 1e-9
+        exposed += x_ns * 1e-9
+    n = len(devs)
+    return Reduction(window_s=(hi - lo) * 1e-9, busy_s=busy / n,
                      ops=ops, calls=calls, gap_list=gap_list,
-                     n_devices=len(devs), kernels=custom_calls(hlo_text))
+                     n_devices=n, kernels=custom_calls(hlo_text),
+                     collective_s=coll / n, exposed_collective_s=exposed / n)

@@ -2,9 +2,12 @@
 
 A configuration's ``layout(conf)`` gives the program's parameter tree with
 (shape, dtype, init) leaves; ``make`` draws every leaf on the device in one
-jitted call, in the type it is served in.  The correctness reference calls
-the same function, so it starts from the same numbers without taking them
-from the program.
+jitted call, in the type it is served in, straight into the shardings it
+is given: no device holds a whole leaf that its sharding splits.  JAX's
+random draws do not depend on how their output is sharded, so the numbers
+are the same bits on any mesh.  The correctness reference calls the same
+function, so it starts from the same numbers without taking them from the
+program.
 """
 from __future__ import annotations
 
@@ -25,10 +28,12 @@ def key_of(seed: int, tag: int) -> jax.Array:
     return jax.random.fold_in(k, tag)
 
 
-def make(layout, seed: int):
+def make(layout, seed: int, shardings=None):
+    """The weights of ``layout`` from ``seed``; ``shardings``, a tree of
+    the layout's structure, places each leaf (the default device without
+    it)."""
     leaves, tree = jax.tree.flatten(layout, is_leaf=is_leaf)
 
-    @jax.jit
     def draw(key):
         keys = jax.random.split(key, len(leaves))
         out = []
@@ -42,7 +47,9 @@ def make(layout, seed: int):
                 out.append(x.astype(dtype))
         return jax.tree.unflatten(tree, out)
 
-    return draw(key_of(seed, WEIGHTS_TAG))
+    placed = jax.jit(draw) if shardings is None else \
+        jax.jit(draw, out_shardings=shardings)
+    return placed(key_of(seed, WEIGHTS_TAG))
 
 
 def abstract(layout):
